@@ -14,8 +14,8 @@ Claims (wired into ``--claims-strict`` CI):
 * throughput — fused pack beats the replaced pipeline by >=2x at the
   4 MiB gradient point (transformer-like tree, d=88 x 12 layers);
 * wire bytes — the quantized wire is >=3.5x smaller than the f32 wire;
-* roofline — the fused kernel is bandwidth-bound on the deployment HW
-  model (:class:`repro.roofline.analysis.HW`): arithmetic intensity far
+* roofline — the fused kernel is bandwidth-bound on TPU v5e's published
+  peaks (:func:`repro.roofline.analysis.peaks`): arithmetic intensity far
   below the ridge, memory term >=90% of the modeled kernel time.  The
   flop/byte counts are per element: 9 f32 ops (ef-add, abs, max, div,
   round, 2x clip, sub, mul) over 13 bytes moved (read g + ef, write q +
@@ -33,7 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.grad_pack import pack_grads_fused, unpack_grads_fused
-from repro.roofline.analysis import HW
+from repro.roofline.analysis import DRYRUN_KIND, peaks
 from repro.train.grad_sync import compress_grads_int8_ef, pack_grads
 
 from .common import Claim, save_result, table
@@ -51,7 +51,7 @@ FLOPS_PER_ELEM = 9.0
 BYTES_PER_ELEM = 13.0
 
 
-def _grad_tree(d: int, layers: int, seed: int = 0):
+def grad_tree(d: int, layers: int, seed: int = 0):
     """Transformer-ish gradient pytree: 12*d^2 + 2*d params per layer."""
     rng = np.random.default_rng(seed)
 
@@ -99,14 +99,17 @@ def _best_of(fn, tree, reps: int):
     return best, nbytes
 
 
-def roofline_placement(hw: HW = HW()) -> dict:
-    """Analytic placement of the fused kernel on the deployment roofline
-    (per-element counts, size-independent)."""
+def roofline_placement(device_kind: str = DRYRUN_KIND) -> dict:
+    """Analytic placement of the fused kernel on ``device_kind``'s published
+    roofline (per-element counts, size-independent); an unknown device
+    raises."""
+    hw = peaks(device_kind)
     ai = FLOPS_PER_ELEM / BYTES_PER_ELEM
     ridge = hw.peak_flops / hw.hbm_bw
     compute_s = FLOPS_PER_ELEM / hw.peak_flops  # per element
     memory_s = BYTES_PER_ELEM / hw.hbm_bw
     return {
+        "device_kind": device_kind,
         "arithmetic_intensity": ai,
         "ridge": ridge,
         "memory_fraction": memory_s / (memory_s + compute_s),
@@ -121,7 +124,7 @@ def run(fast: bool = False) -> dict:
     data: dict = {"points": {}}
     ratio_at_claim = wire_ratio_at_claim = 0.0
     for d, layers in ladder:
-        tree = _grad_tree(d, layers, seed=d)
+        tree = grad_tree(d, layers, seed=d)
         # warm both compilation caches outside the timed region
         _old_pipeline(tree, _zeros_ef(tree))
         _fused_pipeline(tree, _zeros_ef(tree))
@@ -159,7 +162,8 @@ def run(fast: bool = False) -> dict:
     ]
     print(table(rows, ["point", "grads", "old", "fused", "speedup", "wire"],
                 "Grad-sync pack: replaced pipeline vs fused device kernel"))
-    print(f"roofline: AI={roof['arithmetic_intensity']:.2f} flop/B, "
+    print(f"roofline ({roof['device_kind']} published peaks): "
+          f"AI={roof['arithmetic_intensity']:.2f} flop/B, "
           f"ridge={roof['ridge']:.0f}, {roof['bound']}-bound "
           f"(memory term {roof['memory_fraction']*100:.1f}% of modeled time)")
     print(table([c.row() for c in claims], ["figure", "claim", "paper", "achieved", "status"]))

@@ -16,7 +16,8 @@ f32 scales, u32 offset table — that goes to the wire via a single
 same format) rebuilds the pytree.
 
 Kernel shape: leaves are flattened, zero-padded to :data:`wire.PACK_TILE`
-elements, and concatenated; a scalar-prefetched ``seg_ids`` table maps
+elements, and concatenated; each tile enters the kernel as one whole
+``(8, 128)`` block, and a scalar-prefetched ``seg_ids`` table maps
 each tile to its leaf.  Grid ``(2, n_tiles)`` makes two sequential passes:
 
 * phase 0 — per-tile ``max(|g+ef|)`` folded into a per-leaf running max
@@ -25,7 +26,7 @@ each tile to its leaf.  Grid ``(2, n_tiles)`` makes two sequential passes:
   tile, emit the int8 payload tile + the f32 error-feedback tile, and on
   the last tile flush the scales vector.
 
-The payload/ef output index map is ``(i, j) -> (i*j, 0)``: every phase-0
+The payload/ef output index map is ``(i, j) -> (i*j, 0, 0)``: every phase-0
 step aliases block 0, so each output block's visits form one consecutive
 run (Pallas's revisit rule) and the real writes all happen in phase 1.
 
@@ -52,11 +53,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core.comm import wire
-from .compat import CompilerParams
 
 __all__ = ["pack_grads_fused", "unpack_grads_fused", "packed_nbytes"]
 
 TILE = wire.PACK_TILE
+ROWS = TILE // 128  # one (8, 128) f32 vreg tile per grid step
 
 # Error-feedback update, in every path (host numpy / XLA / Mosaic):
 #
@@ -72,8 +73,30 @@ TILE = wire.PACK_TILE
 # identically, everywhere.  The scale likewise uses an explicit
 # reciprocal multiply (see _RECIP127): XLA strength-reduces
 # division-by-constant into reciprocal multiplication, which is 1 ulp off
-# IEEE division for some inputs.
+# IEEE division for some inputs.  For the same reason the kernel divides
+# with :func:`_div_rn`: Mosaic lowers f32 ``a / b`` to ``a * recip(b)``.
 _RECIP127 = float(np.float32(1.0) / np.float32(127.0))
+
+
+def _div_rn(a, b):
+    """IEEE ``a / b`` (f32, round to nearest even) for ``b > 0`` normal,
+    by restoring long division on the 24-bit significands in int32, so the
+    result is the same on every backend.  Subnormal ``a`` and quotients
+    below the normal range give zero, as XLA's CPU and TPU backends flush."""
+    i32 = lambda x: jax.lax.bitcast_convert_type(x, jnp.int32)
+    ia, ib = i32(a), i32(b)
+    ea = (ia >> 23) & 0xFF
+    ma, mb = (ia & 0x7FFFFF) | 0x800000, (ib & 0x7FFFFF) | 0x800000
+    lt = (ma < mb).astype(jnp.int32)
+    exp = ea - ((ib >> 23) & 0xFF) + 127 - lt
+    rem, q = ma << lt, jnp.zeros_like(ia)  # mb <= rem < 2 mb: the first bit is 1
+    for _ in range(25):  # 24 significand bits and a guard bit
+        bit = (rem >= mb).astype(jnp.int32)
+        rem = (rem - bit * mb) << 1
+        q = (q << 1) | bit
+    q = (q >> 1) + ((q & 1) & ((rem != 0) | ((q >> 1) & 1)).astype(jnp.int32))
+    mag = jax.lax.bitcast_convert_type(((exp - 1) << 23) + q, jnp.float32)  # a carry bumps exp
+    return jnp.where((ea == 0) | (exp <= 0), np.float32(0.0), jnp.where(a < 0, -mag, mag))
 
 
 # ---------------------------------------------------------------------------
@@ -85,7 +108,7 @@ def _pack_kernel(n_tiles, seg_ref, g_ref, ef_ref, payload_ref, scales_ref, ef_ou
     phase = pl.program_id(0)
     j = pl.program_id(1)
     s = seg_ref[j]
-    g32 = g_ref[...] + ef_ref[...]  # (1, TILE) f32 — the fused EF add
+    g32 = g_ref[0] + ef_ref[0]  # (ROWS, 128) f32 — the fused EF add
 
     @pl.when((phase == 0) & (j == 0))
     def _init():
@@ -93,18 +116,16 @@ def _pack_kernel(n_tiles, seg_ref, g_ref, ef_ref, payload_ref, scales_ref, ef_ou
 
     @pl.when(phase == 0)
     def _max_pass():
-        m = jnp.max(jnp.abs(g32))
-        cur = pl.load(maxabs_ref, (slice(0, 1), pl.dslice(s, 1)))
-        pl.store(maxabs_ref, (slice(0, 1), pl.dslice(s, 1)), jnp.maximum(cur, m[None, None]))
+        m = jnp.max(jnp.max(jnp.abs(g32), axis=0, keepdims=True), axis=1, keepdims=True)
+        maxabs_ref[s] = jnp.maximum(maxabs_ref[s], m)
 
     @pl.when(phase == 1)
     def _quant_pass():
-        ma = pl.load(maxabs_ref, (slice(0, 1), pl.dslice(s, 1)))[0, 0]
-        scale = jnp.maximum(ma, 1e-12) * _RECIP127
-        r = g32 / scale
-        q = jnp.clip(jnp.round(r), -127, 127).astype(jnp.int8)
-        payload_ref[...] = q
-        ef_out_ref[...] = (r - q.astype(jnp.float32)) * scale
+        scale = jnp.maximum(maxabs_ref[s], 1e-12) * _RECIP127  # (1, 128), lanes equal
+        r = _div_rn(g32, scale)
+        q = jnp.clip(jnp.round(r), -127, 127)
+        payload_ref[0] = q.astype(jnp.int32).astype(jnp.int8)
+        ef_out_ref[0] = (r - q) * scale
 
         @pl.when(j == n_tiles - 1)
         def _flush_scales():
@@ -112,34 +133,39 @@ def _pack_kernel(n_tiles, seg_ref, g_ref, ef_ref, payload_ref, scales_ref, ef_ou
 
 
 def _pallas_pack(g_tiles, ef_tiles, seg_ids, n_leaves, *, interpret):
+    """Tiles go in as ``(n_tiles, ROWS, 128)`` so every block is one whole
+    (8, 128) vreg tile; the per-leaf running max lives in a leading-dim
+    indexed ``(n_leaves, 1, 128)`` scratch (lanes hold copies), so the
+    kernel needs no dynamic lane slice."""
     n_tiles = g_tiles.shape[0]
+    tile = lambda i, j, seg: (j, 0, 0)
+    out_tile = lambda i, j, seg: (i * j, 0, 0)
+    whole = lambda i, j, seg: (0, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(2, n_tiles),
-        in_specs=[
-            pl.BlockSpec((1, TILE), lambda i, j, seg: (j, 0)),
-            pl.BlockSpec((1, TILE), lambda i, j, seg: (j, 0)),
-        ],
+        in_specs=[pl.BlockSpec((1, ROWS, 128), tile), pl.BlockSpec((1, ROWS, 128), tile)],
         out_specs=[
-            pl.BlockSpec((1, TILE), lambda i, j, seg: (i * j, 0)),
-            pl.BlockSpec((1, n_leaves), lambda i, j, seg: (0, 0)),
-            pl.BlockSpec((1, TILE), lambda i, j, seg: (i * j, 0)),
+            pl.BlockSpec((1, ROWS, 128), out_tile),
+            pl.BlockSpec((n_leaves, 1, 128), whole),
+            pl.BlockSpec((1, ROWS, 128), out_tile),
         ],
-        scratch_shapes=[pltpu.VMEM((1, n_leaves), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((n_leaves, 1, 128), jnp.float32)],
     )
-    return pl.pallas_call(
+    q, scales, ef_out = pl.pallas_call(
         functools.partial(_pack_kernel, n_tiles),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((n_tiles, TILE), jnp.int8),
-            jax.ShapeDtypeStruct((1, n_leaves), jnp.float32),
-            jax.ShapeDtypeStruct((n_tiles, TILE), jnp.float32),
+            jax.ShapeDtypeStruct((n_tiles, ROWS, 128), jnp.int8),
+            jax.ShapeDtypeStruct((n_leaves, 1, 128), jnp.float32),
+            jax.ShapeDtypeStruct((n_tiles, ROWS, 128), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,
-    )(seg_ids, g_tiles, ef_tiles)
+    )(seg_ids, g_tiles.reshape(n_tiles, ROWS, 128), ef_tiles.reshape(n_tiles, ROWS, 128))
+    return q, scales[:, 0, 0], ef_out
 
 
 def _xla_pack(g_tiles, ef_tiles, seg_ids, n_leaves):
@@ -156,7 +182,7 @@ def _xla_pack(g_tiles, ef_tiles, seg_ids, n_leaves):
     r = tiles / st
     q = jnp.clip(jnp.round(r), -127, 127).astype(jnp.int8)
     ef_out = (r - q.astype(jnp.float32)) * st
-    return q, scale[None, :], ef_out
+    return q, scale, ef_out
 
 
 # ---------------------------------------------------------------------------

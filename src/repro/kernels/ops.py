@@ -1,13 +1,17 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-The models call these through the ``kernel_impl`` switch (config/env):
-``"xla"`` (default — reference lowering, used by the dry-run and CPU
-tests) or ``"pallas"`` (TPU deployment; ``interpret=True`` on CPU).
-Numerics contracts are pinned by tests against :mod:`repro.kernels.ref`.
+The models call these through :func:`kernel_mode`, read from
+``REPRO_KERNELS`` at trace time: ``"pallas"`` (the default on a TPU
+backend), ``"xla"`` (the reference lowering; the default elsewhere, and
+the explicit reference switch on the chip) or ``"pallas-interpret"``
+(the kernels in the Pallas interpreter; CPU only).  Numerics contracts
+are pinned by tests against :mod:`repro.kernels.ref`.
 """
 from __future__ import annotations
 
+import contextlib
 import os
+import threading
 from typing import Optional, Tuple
 
 import jax
@@ -25,15 +29,47 @@ __all__ = [
     "attention",
     "expert_ffn_matmul",
     "kernel_mode",
+    "reference_lowering",
 ]
 
 
+KERNEL_MODES = ("pallas", "pallas-interpret", "xla")
+
+_ctx = threading.local()
+
+
+@contextlib.contextmanager
+def reference_lowering():
+    """Trace the enclosed model code with the ``xla`` reference lowering,
+    whatever ``REPRO_KERNELS`` says.  The train step differentiates under
+    it: the kernels are forward-only (no VJP) and GSPMD cannot partition
+    a Mosaic call."""
+    prev = getattr(_ctx, "reference", False)
+    _ctx.reference = True
+    try:
+        yield
+    finally:
+        _ctx.reference = prev
+
+
 def kernel_mode() -> str:
-    """'pallas' | 'pallas-interpret' | 'xla' (default on CPU)."""
+    """'pallas' (default on TPU) | 'xla' (default elsewhere) | 'pallas-interpret'.
+
+    Interpret mode on a TPU backend is an error, not a silent slow path."""
+    if getattr(_ctx, "reference", False):
+        return "xla"
     mode = os.environ.get("REPRO_KERNELS", "")
-    if mode:
-        return mode
-    return "pallas" if jax.default_backend() == "tpu" else "xla"
+    on_tpu = jax.default_backend() == "tpu"
+    if not mode:
+        return "pallas" if on_tpu else "xla"
+    if mode not in KERNEL_MODES:
+        raise ValueError(f"REPRO_KERNELS={mode!r}; expected one of {KERNEL_MODES}")
+    if on_tpu and mode == "pallas-interpret":
+        raise RuntimeError(
+            "REPRO_KERNELS=pallas-interpret on a TPU backend: the chip would run the "
+            "Pallas interpreter; use 'pallas', or 'xla' for the reference lowering"
+        )
+    return mode
 
 
 def attention(q, k, v, *, causal=True, window=0, chunk=0) -> jax.Array:

@@ -26,31 +26,36 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
 
 __all__ = ["ssd_chunk_kernel"]
 
 
 def _ssd_kernel(a_ref, x_ref, b_ref, c_ref, y_ref, s_ref):
-    # a: (1,1,1,Q)  x: (1,1,1,Q,P)  b,c: (1,1,1,Q,N)
-    a = a_ref[0, 0, 0].astype(jnp.float32)  # (Q,)
+    # a: (1,1,1,1,Q)  x: (1,1,1,Q,P)  b,c: (1,1,1,Q,N)
+    a = a_ref[0, 0, 0].astype(jnp.float32)  # (1,Q) — one lane row
     x = x_ref[0, 0, 0].astype(jnp.float32)  # (Q,P)
     b = b_ref[0, 0, 0].astype(jnp.float32)  # (Q,N)
     c = c_ref[0, 0, 0].astype(jnp.float32)  # (Q,N)
-    q = a.shape[0]
-    acs = jnp.cumsum(a)  # (Q,)
-    # L[i,j] = exp(acs_i - acs_j) for j <= i else 0
-    diff = acs[:, None] - acs[None, :]
+    q = a.shape[-1]
     li = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
     lj = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
-    L = jnp.where(lj <= li, jnp.exp(diff), 0.0)
-    g = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    causal = lj <= li
+    # Prefix sums of A·dt as f32 matmuls against the causal mask: Mosaic
+    # has no cumsum, and the column form is needed next to the row form.
+    tri = causal.astype(jnp.float32)
+    nt = (((1,), (1,)), ((), ()))
+    exact = jax.lax.Precision.HIGHEST
+    acs_col = jax.lax.dot_general(tri, a, nt, precision=exact, preferred_element_type=jnp.float32)  # (Q,1)
+    acs_row = jax.lax.dot_general(a, tri, nt, precision=exact, preferred_element_type=jnp.float32)  # (1,Q)
+    # L[i,j] = exp(acs_i - acs_j) for j <= i else 0
+    L = jnp.exp(jnp.where(causal, acs_col - acs_row, -jnp.inf))
+    g = jax.lax.dot_general(c, b, nt, preferred_element_type=jnp.float32)
     y = jax.lax.dot(g * L, x, preferred_element_type=jnp.float32)  # (Q,P)
     y_ref[0, 0, 0] = y.astype(y_ref.dtype)
-    decay = jnp.exp(acs[-1] - acs)  # (Q,)
-    bw = b * decay[:, None]  # (Q,N)
-    state = jax.lax.dot_general(x, bw, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    s_ref[0, 0, 0] = state.astype(s_ref.dtype)  # (P,N)
+    decay = jnp.exp(acs_row[:, q - 1 :] - acs_col)  # (Q,1)
+    bw = b * decay  # (Q,N)
+    state = jax.lax.dot(x.T, bw, preferred_element_type=jnp.float32)  # (P,N)
+    s_ref[0, 0, 0] = state.astype(s_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -73,7 +78,7 @@ def ssd_chunk_kernel(
         _ssd_kernel,
         grid=(bsz, h, nc),
         in_specs=[
-            pl.BlockSpec((1, 1, 1, q), lambda b_, h_, c_: (b_, h_, c_, 0)),
+            pl.BlockSpec((1, 1, 1, 1, q), lambda b_, h_, c_: (b_, h_, c_, 0, 0)),
             pl.BlockSpec((1, 1, 1, q, p), lambda b_, h_, c_: (b_, h_, c_, 0, 0)),
             pl.BlockSpec((1, 1, 1, q, n), lambda b_, h_, c_: (b_, h_ // rep, c_, 0, 0)),
             pl.BlockSpec((1, 1, 1, q, n), lambda b_, h_, c_: (b_, h_ // rep, c_, 0, 0)),
@@ -83,8 +88,8 @@ def ssd_chunk_kernel(
             pl.BlockSpec((1, 1, 1, p, n), lambda b_, h_, c_: (b_, h_, c_, 0, 0)),
         ],
         out_shape=[y_shape, s_shape],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
         ),
         interpret=interpret,
-    )(a_dt, x, b, c)
+    )(a_dt.reshape(bsz, h, nc, 1, q), x, b, c)

@@ -4,13 +4,17 @@ A function (not a module constant) so importing never touches jax device
 state.  Single pod: 16×16 = 256 chips (v5e pod), axes (data, model).
 Multi-pod: 2×16×16 = 512 chips, axes (pod, data, model) — the pod axis is
 pure data parallelism over DCN in the baseline layout.
+
+Every mesh is built with ``Auto`` axes: the model code places activations
+with ``with_sharding_constraint`` through the logical rules, which an
+``Explicit`` mesh (``jax.make_mesh``'s default since JAX 0.7) refuses.
 """
 from __future__ import annotations
 
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 from ..sharding.logical import DEFAULT_TABLE, ShardingRules
 
@@ -20,11 +24,11 @@ __all__ = ["make_production_mesh", "make_rules", "make_test_mesh"]
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_test_mesh(shape, axes)
 
 
 def make_test_mesh(shape: Tuple[int, ...] = (2, 2), axes: Tuple[str, ...] = ("data", "model")) -> Mesh:
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_rules(mesh: Mesh, *, long_context: bool = False, overrides: Optional[dict] = None) -> ShardingRules:

@@ -16,6 +16,7 @@ from ..optim import OptHParams
 from ..sharding.logical import use_rules
 from ..train import TrainConfig
 from ..train.trainer import Trainer, TrainerConfig
+from .compile_cache import enable_compile_cache
 from .mesh import make_production_mesh, make_rules
 
 
@@ -40,6 +41,7 @@ def main() -> int:
     ap.add_argument("--multi-pod", action="store_true")
     args = ap.parse_args()
 
+    enable_compile_cache()
     arch = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     hp = OptHParams(lr_peak=args.lr, warmup_steps=max(args.steps // 10, 1), total_steps=args.steps)
     tcfg = TrainConfig(microbatches=args.microbatches, remat=args.remat,

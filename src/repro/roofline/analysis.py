@@ -1,4 +1,4 @@
-"""Three-term roofline from dry-run artifacts (TPU v5e constants).
+"""Three-term roofline from dry-run artifacts, against a chip's published peaks.
 
 For every compiled (arch × shape × mesh) cell::
 
@@ -18,6 +18,8 @@ Methodology notes (documented, consistent across cells):
   divided by one ICI link — a deliberately conservative single-link model;
   multi-link speedup is an optimization the §Perf log must earn by
   splitting traffic across mesh axes.
+* the peaks come from :data:`PEAKS`, keyed by ``Device.device_kind``; the
+  dry-run's virtual mesh models TPU v5e (:data:`DRYRUN_KIND`).
 * MODEL_FLOPS = 6·N_active·tokens (train) / 2·N_active·tokens (inference)
   — the "useful work" yardstick; ``flops_ratio`` = MODEL/HLO catches
   remat and padding waste; ``roofline_fraction`` = ideal-compute-time /
@@ -32,17 +34,34 @@ from typing import Dict, List, Optional
 
 from ..configs import SHAPES, get_config
 
-__all__ = ["HW", "RooflineCell", "analyze_cell", "load_cells", "format_table"]
+__all__ = ["HW", "PEAKS", "DRYRUN_KIND", "peaks", "RooflineCell", "analyze_cell", "load_cells", "format_table"]
 
 
 @dataclass(frozen=True)
 class HW:
-    peak_flops: float = 197e12  # bf16 per chip
-    hbm_bw: float = 819e9  # bytes/s per chip
-    ici_link_bw: float = 50e9  # bytes/s per link
+    peak_flops: float  # bf16 FLOP/s per chip
+    hbm_bw: float  # bytes/s per chip
+    ici_link_bw: float  # bytes/s per link
 
 
-DEFAULT_HW = HW()
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# TPU v5e — Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+# 819 GB/s HBM, 1,600 Gbit/s of ICI per chip over 4 links (50 GB/s each).
+PEAKS = {
+    "TPU v5 lite": HW(peak_flops=197e12, hbm_bw=819e9, ici_link_bw=50e9),
+}
+
+DRYRUN_KIND = "TPU v5 lite"  # the chip the dry-run's virtual mesh models
+
+
+def peaks(device_kind: str) -> HW:
+    """The published peaks of ``device_kind``; an unknown device raises."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; add them to PEAKS with their source"
+        ) from None
 
 
 @dataclass
@@ -112,9 +131,10 @@ def decode_min_bytes(arch_name: str, shape_name: str) -> float:
     return param_bytes + cache
 
 
-def analyze_cell(rec: Dict, hw: HW = DEFAULT_HW) -> Optional[RooflineCell]:
+def analyze_cell(rec: Dict, hw: Optional[HW] = None) -> Optional[RooflineCell]:
     if rec.get("status") != "ok":
         return None
+    hw = hw or peaks(DRYRUN_KIND)
     nd = rec["n_devices"]
     dot_flops = rec.get("dot_flops", 0.0)  # per device
     # memory term: loop-aware materialized-op bytes when available (reflects
@@ -153,7 +173,7 @@ def analyze_cell(rec: Dict, hw: HW = DEFAULT_HW) -> Optional[RooflineCell]:
     )
 
 
-def load_cells(dry_dir: str, mesh_filter: Optional[str] = None, hw: HW = DEFAULT_HW) -> List[RooflineCell]:
+def load_cells(dry_dir: str, mesh_filter: Optional[str] = None, hw: Optional[HW] = None) -> List[RooflineCell]:
     out = []
     for p in sorted(Path(dry_dir).glob("*.json")):
         rec = json.loads(p.read_text())

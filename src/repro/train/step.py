@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ArchConfig
+from ..kernels.ops import reference_lowering
 from ..models import model as model_lib
 from ..optim import OptHParams, adamw_init, adamw_update
 from ..sharding.logical import shard
@@ -79,7 +80,8 @@ def make_train_step(
     tcfg: TrainConfig = TrainConfig(),
 ) -> Callable[[TrainState, Dict[str, jax.Array]], Tuple[TrainState, Dict[str, jax.Array]]]:
     def loss(params, mb):
-        total, metrics = model_lib.loss_fn(params, cfg, mb, remat=tcfg.remat)
+        with reference_lowering():
+            total, metrics = model_lib.loss_fn(params, cfg, mb, remat=tcfg.remat)
         return total, metrics
 
     grad_fn = jax.value_and_grad(loss, has_aux=True)

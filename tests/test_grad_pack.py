@@ -221,6 +221,27 @@ def test_fused_pack_edge_trees():
     np.testing.assert_allclose(np.asarray(back2["w"]), np.ones(3), atol=0.01)
 
 
+def test_kernel_division_is_ieee_division():
+    """The kernel divides with ``_div_rn`` because Mosaic lowers f32 ``a / b``
+    to a reciprocal multiply; ``_div_rn`` must equal numpy's IEEE division
+    bit for bit, at the half-integer knife edges of the quantizer too."""
+    from repro.kernels.grad_pack import _div_rn
+
+    rng = np.random.default_rng(0)
+    n = 1 << 16
+    b = np.exp2(rng.uniform(-46, 40, 4 * n)).astype(np.float32)
+    wide = (rng.standard_normal(n) * np.exp2(rng.integers(-60, 60, n))).astype(np.float32)
+    half = ((rng.integers(-127, 127, n) + 0.5) * b[n : 2 * n]).astype(np.float32)
+    a = np.concatenate([wide, half, np.nextafter(half, np.inf), np.nextafter(half, -np.inf)])
+    a[:6] = [0.0, -0.0, 1e-40, -1e-40, 127.0, -3.0]
+    want = a / b
+    got = np.asarray(jax.jit(_div_rn)(a, b))
+    tiny = np.float32(2.0**-126)
+    normal = (np.abs(want) >= tiny) & (np.abs(a) >= tiny)
+    np.testing.assert_array_equal(got[normal], want[normal])
+    assert not got[~normal].any()  # subnormal in or out: flushed to zero
+
+
 def test_unpack_grads_reads_fused_wire():
     """The host unpacker and the fused unpacker agree on KIND_Q8 bytes —
     one wire format, two consumers."""

@@ -93,13 +93,13 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
 from repro.configs import SMOKES
-from repro.launch.mesh import make_rules
+from repro.launch.mesh import make_rules, make_test_mesh
 from repro.optim import OptHParams
 from repro.sharding.logical import use_rules
 from repro.sharding.params import batch_specs, param_specs, tree_shardings
 from repro.train import TrainConfig, init_train_state, make_train_step
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_test_mesh((4, 2))
 rules = make_rules(mesh)
 cfg = SMOKES["tinyllama-1.1b"]
 with use_rules(rules), mesh:
@@ -129,11 +129,11 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
 from repro.configs import SMOKES
-from repro.launch.mesh import make_rules
+from repro.launch.mesh import make_rules, make_test_mesh
 from repro.models import forward_train, init_params
 from repro.sharding.logical import use_rules
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_test_mesh((2, 4))
 for name in ("qwen2-7b", "minicpm3-4b"):
     cfg = SMOKES[name].variant(dtype="float32", n_heads=6, n_kv_heads=2 if name=="qwen2-7b" else 6)
     if name == "minicpm3-4b":

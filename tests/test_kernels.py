@@ -163,3 +163,25 @@ def test_flash_attention_equals_model_attention_core():
     finally:
         os.environ["REPRO_KERNELS"] = "xla"
     assert float(jnp.max(jnp.abs(out - ref))) < 5e-5
+
+
+@pytest.mark.parametrize("env,backend,want", [
+    ("", "tpu", "pallas"),
+    ("", "cpu", "xla"),
+    ("xla", "tpu", "xla"),
+    ("pallas-interpret", "cpu", "pallas-interpret"),
+    ("pallas-interpret", "tpu", RuntimeError),
+    ("pallsa", "cpu", ValueError),
+])
+def test_kernel_mode_resolution(monkeypatch, env, backend, want):
+    """Interpret mode is refused on a TPU backend; a misspelt mode is an
+    error rather than a silent fall-through to the reference lowering."""
+    from repro.kernels import ops
+
+    monkeypatch.setenv("REPRO_KERNELS", env)
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: backend)
+    if isinstance(want, str):
+        assert ops.kernel_mode() == want
+    else:
+        with pytest.raises(want):
+            ops.kernel_mode()
