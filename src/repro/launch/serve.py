@@ -140,6 +140,8 @@ def main() -> int:
     if args.workers > 1:
         extra = f" eagain={server.eagain_events}"
         server.close()
+    else:
+        extra = f" host_syncs={server.core.host_syncs} compiles={server.core.compiles}"
     print(
         f"requests={len(done)}/{len(reqs)} engine_steps={server.steps} "
         f"tokens={server.tokens_out} throughput={server.tokens_out/dt:.1f} tok/s "

@@ -520,7 +520,6 @@ class Router:
                 req.first_token_at = now
             req.out_tokens.append(tok)
             if done:
-                req.finished_at = now
                 req.done_event.set()
                 self.completed += 1
                 wid = self._sticky.pop(rid, None)

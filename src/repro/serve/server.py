@@ -27,6 +27,17 @@ Since ISSUE 7 the slot scheduler + batched decode live in
 the fleet's :class:`~repro.serve.fleet.ModelWorker` — the fleet shards
 the slot space across workers but runs the SAME math, which is what makes
 the token-stream equivalence tests exact rather than approximate.
+
+**Spans and counters.** Each stage of the loop opens a
+``jax.profiler.TraceAnnotation`` under the ``serve.`` prefix (``serve.comm``,
+``serve.admit`` with its ``.scratch`` / ``.prefill`` / ``.splice`` /
+``.first_token`` children, ``serve.decode`` with ``.dispatch`` / ``.sync`` /
+``.emit``, ``serve.flush`` and ``serve.deliver``).  They are inert unless a
+profiler session is open, and then land on the device trace's clock.
+``serve.admit`` carries the request's ``rid`` and ``queued_ns``, admission
+start less the client's ``submitted_at``.  ``DecodeCore.host_syncs`` counts
+the device-to-host reads that block the loop and ``DecodeCore.compiles`` the
+growth of its jit caches.
 """
 from __future__ import annotations
 
@@ -40,6 +51,7 @@ from typing import Any, Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..configs.base import ArchConfig
 from ..core.comm.collective import CommChannel
@@ -88,7 +100,6 @@ class Request:
     done_event: threading.Event = field(default_factory=threading.Event)
     submitted_at: float = 0.0
     first_token_at: Optional[float] = None
-    finished_at: Optional[float] = None
 
 
 # emit(req, token, done) — one generated token leaves the model side.
@@ -167,9 +178,14 @@ class DecodeCore:
             return jax.tree.map(leaf, full, piece)
 
         self._splice = jax.jit(_splice, donate_argnums=(0,))
+        # the jitted programs as built, whose caches `compiles` watches
+        self._jits = (self._prefill_one, self._decode, self._splice)
+        self._jit_entries = 0  # compiled programs in their caches, last look
         self.steps = 0
         self.tokens_out = 0
         self.prefill_calls = 0  # single-shot prefill dispatches (0 when chunked)
+        self.host_syncs = 0  # device-to-host reads that block the loop
+        self.compiles = 0  # entries the jit caches above grew by
         # worst prompt-tokens-of-prefill-work attributed to a single engine
         # step — the burst chunked prefill exists to bound (≤ active slots
         # per step vs a whole prompt per admission single-shot)
@@ -194,40 +210,57 @@ class DecodeCore:
         keeps the slot in the prefilling state until :meth:`feed_chunk`
         delivers the rest.  Returns the slot index."""
         slot = self.free_slots()[0]
-        if self.prefill_chunk > 0:
-            prompt = req.prompt if more_chunks else req.prompt[: self.max_prefill]
-            # reset the recycled row (zero KV, position tags = -1), then
-            # consume the prompt one token per step through decode_step
-            self.cache = self._splice(self.cache, self._fresh_row, slot)
-            self._slots[slot] = req
-            self._positions[slot] = 0
-            self._remaining[slot] = req.max_new
-            self._prefill_queue[slot] = deque(prompt)
-            self._prefill_open[slot] = more_chunks
-            self._rid_slot[req.rid] = slot
-            return slot
-        prompt = req.prompt[: self.max_prefill]
-        toks = np.zeros((1, self.max_prefill), np.int32)
-        toks[0, -len(prompt) :] = prompt  # left-pad; ring positions still 0..n
-        # single-sequence prefill on a scratch cache, then splice into slot
-        one = init_cache(self.arch, 1, self.context)
-        batch = {"tokens": jnp.asarray(toks[:, -len(prompt) :])}
-        logits, one = self._prefill_one(self.params, batch, one)
-        self.prefill_calls += 1
-        self._pending_burst += len(prompt)
-        self.cache = self._splice(self.cache, one, slot)
-        tok = int(jnp.argmax(logits[0, -1]))
-        done = req.max_new <= 1
-        self._slots[slot] = None if done else req
-        self._positions[slot] = len(prompt)
-        self._remaining[slot] = req.max_new - 1
-        self._last_tok[slot] = tok
-        self._rid_slot[req.rid] = slot
-        if done:
-            self._rid_slot.pop(req.rid, None)
-        self.tokens_out += 1
-        emit(req, tok, done)
+        chunked = self.prefill_chunk > 0
+        prompt = req.prompt if chunked and more_chunks else req.prompt[: self.max_prefill]
+        # a request with no client stamp (the fleet's own format) reads 0
+        queued_ns = int((time.monotonic() - req.submitted_at) * 1e9) if req.submitted_at else 0
+        with TraceAnnotation("serve.admit", rid=req.rid, prompt=len(prompt), queued_ns=queued_ns):
+            if chunked:
+                # reset the recycled row (zero KV, position tags = -1), then
+                # consume the prompt one token per step through decode_step
+                self.cache = self._splice(self.cache, self._fresh_row, slot)
+                self._slots[slot] = req
+                self._positions[slot] = 0
+                self._remaining[slot] = req.max_new
+                self._prefill_queue[slot] = deque(prompt)
+                self._prefill_open[slot] = more_chunks
+                self._rid_slot[req.rid] = slot
+            else:
+                # single-sequence prefill on a scratch cache, then splice into slot
+                with TraceAnnotation("serve.admit.scratch"):
+                    one = init_cache(self.arch, 1, self.context)
+                with TraceAnnotation("serve.admit.prefill"):
+                    toks = np.zeros((1, self.max_prefill), np.int32)
+                    toks[0, -len(prompt) :] = prompt  # left-pad; ring positions still 0..n
+                    batch = {"tokens": jnp.asarray(toks[:, -len(prompt) :])}
+                    logits, one = self._prefill_one(self.params, batch, one)
+                self.prefill_calls += 1
+                self._pending_burst += len(prompt)
+                with TraceAnnotation("serve.admit.splice"):
+                    self.cache = self._splice(self.cache, one, slot)
+                with TraceAnnotation("serve.admit.first_token"):
+                    tok = int(jnp.argmax(logits[0, -1]))
+                self.host_syncs += 1
+                done = req.max_new <= 1
+                self._slots[slot] = None if done else req
+                self._positions[slot] = len(prompt)
+                self._remaining[slot] = req.max_new - 1
+                self._last_tok[slot] = tok
+                self._rid_slot[req.rid] = slot
+                if done:
+                    self._rid_slot.pop(req.rid, None)
+                self.tokens_out += 1
+                emit(req, tok, done)
+        self._note_compiles()
         return slot
+
+    def _note_compiles(self) -> None:
+        # entries of the jit caches (JAX's private `_cache_size()`): a
+        # program loaded from the persistent compile cache counts too
+        prefill_fn, decode_fn, splice_fn = self._jits
+        entries = prefill_fn._cache_size() + decode_fn._cache_size() + splice_fn._cache_size()
+        self.compiles += entries - self._jit_entries
+        self._jit_entries = entries
 
     def feed_chunk(self, rid: int, tokens: List[int], last: bool) -> None:
         """Append a follow-up prompt chunk for an admitted request."""
@@ -251,41 +284,49 @@ class DecodeCore:
         active = [i for i, r in enumerate(self._slots) if r is not None]
         if not active:
             return False
-        fed: Dict[int, int] = {}  # slot -> prompt token fed this step
-        for i in active:
-            q = self._prefill_queue.get(i)
-            if q is None:
-                continue  # plain decoding slot
-            if q:
-                fed[i] = self._last_tok_feed(i, q.popleft())
-            # else: starved mid-prefill — re-feed last token, hold position
-        toks = jnp.asarray(self._last_tok[:, None])
-        pos = jnp.asarray(self._positions)
-        logits, self.cache = self._decode(self.params, toks, pos, self.cache)
-        nxt = np.asarray(jnp.argmax(logits[:, 0, :], axis=-1), np.int32)
-        for i in active:
-            req = self._slots[i]
-            if i in self._prefill_queue:
-                if i not in fed:
-                    continue  # starved: nothing advanced
-                self._positions[i] += 1
-                if self._prefill_queue[i] or self._prefill_open[i]:
-                    continue  # more prompt to consume: no emission yet
-                # the LAST prompt token was just fed: its logits give the
-                # first generated token — the chunked analogue of the
-                # single-shot prefill's argmax(logits[0, -1])
-                del self._prefill_queue[i]
-                del self._prefill_open[i]
-            else:
-                self._positions[i] += 1
-            self._remaining[i] -= 1
-            self._last_tok[i] = nxt[i]
-            done = self._remaining[i] <= 0
-            self.tokens_out += 1
-            emit(req, int(nxt[i]), done)
-            if done:
-                self._slots[i] = None
-                self._rid_slot.pop(req.rid, None)
+        with TraceAnnotation("serve.decode", active=len(active)):
+            fed: Dict[int, int] = {}  # slot -> prompt token fed this step
+            for i in active:
+                q = self._prefill_queue.get(i)
+                if q is None:
+                    continue  # plain decoding slot
+                if q:
+                    fed[i] = self._last_tok_feed(i, q.popleft())
+                # else: starved mid-prefill — re-feed last token, hold position
+            with TraceAnnotation("serve.decode.dispatch"):
+                toks = jnp.asarray(self._last_tok[:, None])
+                pos = jnp.asarray(self._positions)
+                logits, self.cache = self._decode(self.params, toks, pos, self.cache)
+            with TraceAnnotation("serve.decode.sync"):
+                nxt = np.asarray(jnp.argmax(logits[:, 0, :], axis=-1), np.int32)
+            self.host_syncs += 1
+            before = self.tokens_out
+            with TraceAnnotation("serve.decode.emit") as span:
+                for i in active:
+                    req = self._slots[i]
+                    if i in self._prefill_queue:
+                        if i not in fed:
+                            continue  # starved: nothing advanced
+                        self._positions[i] += 1
+                        if self._prefill_queue[i] or self._prefill_open[i]:
+                            continue  # more prompt to consume: no emission yet
+                        # the LAST prompt token was just fed: its logits give the
+                        # first generated token — the chunked analogue of the
+                        # single-shot prefill's argmax(logits[0, -1])
+                        del self._prefill_queue[i]
+                        del self._prefill_open[i]
+                    else:
+                        self._positions[i] += 1
+                    self._remaining[i] -= 1
+                    self._last_tok[i] = nxt[i]
+                    done = self._remaining[i] <= 0
+                    self.tokens_out += 1
+                    emit(req, int(nxt[i]), done)
+                    if done:
+                        self._slots[i] = None
+                        self._rid_slot.pop(req.rid, None)
+                span.set_metadata(tokens=self.tokens_out - before)
+        self._note_compiles()
         self.steps += 1
         burst = self._pending_burst + len(fed)
         if burst > self.max_prefill_burst:
@@ -381,6 +422,12 @@ class InferenceServer:
         self._inflight: Dict[int, Request] = {}  # rid -> client-side Request
         self._inflight_lock = threading.Lock()
         self._outbox: List[tuple] = []  # (rid, tok, done) batch of one step
+        # [requests, responses] dispatched by the engine step that holds
+        # the step lock, a new list each step; at unlock each thread keeps
+        # its own step's list for its serve.comm span (an executor may pump
+        # the engine beside the serve loop)
+        self._step_msgs = [0, 0]
+        self._own_step = threading.local()
         if cfg.transport in ("collective", "shmem"):
             self._channel = CommChannel(limits=cfg.limits, backend=cfg.transport)
             # step_lock=True: the whole engine step runs behind a try-lock
@@ -419,8 +466,10 @@ class InferenceServer:
             with self._inflight_lock:
                 self._inflight[req.rid] = req
             # the request crosses the comm layer as bytes; EAGAIN parks it
-            # in the channel throttle, retried by the engine step
-            self._channel.send_request(encode_msg((req.rid, req.prompt, req.max_new)))
+            # in the channel throttle, retried by the engine step.  The
+            # submit stamp rides along: admission reads queue wait from it.
+            msg = (req.rid, req.prompt, req.max_new, req.submitted_at)
+            self._channel.send_request(encode_msg(msg))
         return req
 
     # -------------------------------------------- the engine's op adapter
@@ -438,10 +487,12 @@ class InferenceServer:
                 return True  # send completion: slot already recycled
             ch.repost(rec.ctx)  # keep the pre-post depth
             if rec.ctx == "request":
-                rid, prompt, max_new = decode_msg(rec.data)
-                self._pending.append(Request(rid=rid, prompt=prompt, max_new=max_new))
+                rid, prompt, max_new, submitted_at = decode_msg(rec.data)
+                self._pending.append(Request(rid=rid, prompt=prompt, max_new=max_new, submitted_at=submitted_at))
+                self._step_msgs[0] += 1
             else:  # response: a token batch for the client side
                 self._apply_response(rec.data)
+                self._step_msgs[1] += 1
             return True
         if kind == "progress":
             return ch.progress()
@@ -450,8 +501,12 @@ class InferenceServer:
         if kind == "drain_retries":
             return ch.drain_retries()
         if kind == "step_trylock":
-            return self._step_lock.acquire(blocking=False)
+            got = self._step_lock.acquire(blocking=False)
+            if got:
+                self._step_msgs = [0, 0]
+            return got
         if kind == "step_unlock":
+            self._own_step.msgs = self._step_msgs
             self._step_lock.release()
             return True
         if kind == "dev_trylock":
@@ -463,7 +518,12 @@ class InferenceServer:
         retries → progress → reap → dispatch)."""
         if self.engine is None:
             return False
-        return run_step(self.engine, self, 0)
+        self._own_step.msgs = (0, 0)  # stays so if another thread held the lock
+        with TraceAnnotation("serve.comm") as span:
+            out = run_step(self.engine, self, 0)
+            requests, responses = self._own_step.msgs
+            span.set_metadata(requests=requests, responses=responses)
+        return out
 
     def _apply_response(self, payload: bytes) -> None:
         """Client side: apply an arrived token batch to its requests.
@@ -473,19 +533,20 @@ class InferenceServer:
         ``_inflight``, and must never report true while another driver
         thread is still mid-application."""
         now = time.monotonic()
-        for rid, tok, done in decode_msg(payload):
-            with self._inflight_lock:
-                req = self._inflight.get(rid)
-            if req is None:
-                continue
-            if req.first_token_at is None:
-                req.first_token_at = now
-            req.out_tokens.append(tok)
-            if done:
-                req.finished_at = now
-                req.done_event.set()
+        batch = decode_msg(payload)
+        with TraceAnnotation("serve.deliver", tokens=len(batch)):
+            for rid, tok, done in batch:
                 with self._inflight_lock:
-                    self._inflight.pop(rid, None)
+                    req = self._inflight.get(rid)
+                if req is None:
+                    continue
+                if req.first_token_at is None:
+                    req.first_token_at = now
+                req.out_tokens.append(tok)
+                if done:
+                    req.done_event.set()
+                    with self._inflight_lock:
+                        self._inflight.pop(rid, None)
 
     def _emit(self, req: Request, tok: int, done: bool) -> None:
         """One generated token leaves the server: directly into the
@@ -498,7 +559,6 @@ class InferenceServer:
                 req.first_token_at = now
             req.out_tokens.append(tok)
             if done:
-                req.finished_at = now
                 req.done_event.set()
         else:
             self._outbox.append((req.rid, tok, done))
@@ -507,7 +567,8 @@ class InferenceServer:
         if self._channel is None or not self._outbox:
             return False
         batch, self._outbox = self._outbox, []
-        self._channel.send_response(encode_msg(batch))
+        with TraceAnnotation("serve.flush", tokens=len(batch)):
+            self._channel.send_response(encode_msg(batch))
         return True
 
     # ----------------------------------------------------------------- engine

@@ -252,6 +252,7 @@ def test_admission_cost_flat_in_slot_count(model):
                 # max_new=1 finishes at the prefill step, freeing the slot,
                 # so repeated admissions time the admission path alone
                 self.rid, self.prompt, self.max_new = rid, [1, 2, 3], 1
+                self.submitted_at = 0.0  # no client stamp: queue wait reads 0
 
         core.admit(_R(0), sink)  # warm the jit caches for this shape
         best = float("inf")
