@@ -461,26 +461,25 @@ def decode_step(
     cross = params.get("cross")
     new_cache = dict(cache)
 
-    layer_xs: Dict[str, Any] = {}
-    if "ssm" in cache:
-        layer_xs["ssm_slice"] = cache["ssm"]
-    if "kv" in cache:
-        layer_xs["kv_slice"] = cache["kv"]
-    if "cross_kv" in cache:
-        layer_xs["cross_slice"] = cache["cross_kv"]
+    # The stacked caches decode writes ride in the carry and are updated in
+    # place at the layer's index, so the donated buffer that comes in is the
+    # one that goes out. Scanned as xs -> ys, XLA copies the whole stack.
+    written = {k: cache[k] for k in ("ssm", "kv") if k in cache}
+    layer_xs = (params["layers"], idxs)
+    if cross is not None:
+        layer_xs += (cache["cross_kv"], cross)
 
     def body(carry, inp):
-        h, sa = carry
-        lp, idx, lc = inp[0], inp[1], inp[2]
+        h, sa, st = carry
+        lp, idx = inp[0], inp[1]
+        lc = jax.tree.map(lambda t: jax.lax.dynamic_index_in_dim(t, idx, 0, keepdims=False), st)
         hn = rms_norm(h, lp["ln1"], cfg.norm_eps)
-        out_lc = dict(lc)
+        out_lc = {}
         fam = cfg.family
         if fam in ("ssm", "hybrid"):
-            a, ns = ssm_mod.ssm_decode(lp["ssm"], hn, cfg, lc["ssm_slice"])
-            out_lc["ssm_slice"] = ns
+            a, out_lc["ssm"] = ssm_mod.ssm_decode(lp["ssm"], hn, cfg, lc["ssm"])
         elif cfg.attn_kind == "mla":
-            a, kvc = attn.mla_decode(lp["attn"], hn, cfg, lc["kv_slice"], positions)
-            out_lc["kv_slice"] = kvc
+            a, out_lc["kv"] = attn.mla_decode(lp["attn"], hn, cfg, lc["kv"], positions)
         else:
             if cfg.attn_kind == "chunked" and cfg.global_every:
                 a, kvc = jax.lax.cond(
@@ -488,15 +487,15 @@ def decode_step(
                     lambda q, c: attn.attention_decode(lp["attn"], q, cfg, c, positions, "full"),
                     lambda q, c: attn.attention_decode(lp["attn"], q, cfg, c, positions, "chunked", cfg.window),
                     hn,
-                    lc["kv_slice"],
+                    lc["kv"],
                 )
             else:
-                a, kvc = attn.attention_decode(lp["attn"], hn, cfg, lc["kv_slice"], positions, cfg.attn_kind, cfg.window)
-            out_lc["kv_slice"] = kvc
+                a, kvc = attn.attention_decode(lp["attn"], hn, cfg, lc["kv"], positions, cfg.attn_kind, cfg.window)
+            out_lc["kv"] = kvc
         h = h + a
         if cross is not None:
-            cp = inp[3]
-            ck, cv_ = lc["cross_slice"]["k"], lc["cross_slice"]["v"]
+            cross_slice, cp = inp[2], inp[3]
+            ck, cv_ = cross_slice["k"], cross_slice["v"]
             hq = rms_norm(h, cp["ln"], cfg.norm_eps)
             q = jnp.einsum("bsd,dhk->bshk", hq, cp["attn"]["wq"])
             g = cfg.n_heads // cfg.n_kv_heads
@@ -527,14 +526,12 @@ def decode_step(
                 return q + f2, sc2
 
             h, sa = jax.lax.cond(idx % cfg.attn_every == 0, with_attn, lambda q, s: (q, s), h, sa)
-        return (h, sa), out_lc
+        st = jax.tree.map(lambda full, piece: jax.lax.dynamic_update_index_in_dim(full, piece, idx, 0), st, out_lc)
+        return (h, sa, st), None
 
     sa0 = cache.get("shared_attn", jnp.zeros((1,), jnp.int32))
-    xs = (params["layers"], idxs, layer_xs) if cross is None else (params["layers"], idxs, layer_xs, cross)
-    (x, sa), out_layer = jax.lax.scan(body, (x, sa0), xs)
-    for src, dst in (("ssm_slice", "ssm"), ("kv_slice", "kv")):
-        if src in out_layer:
-            new_cache[dst] = out_layer[src]
+    (x, sa, written), _ = jax.lax.scan(body, (x, sa0, written), layer_xs)
+    new_cache.update(written)
     if "shared_attn" in cache:
         new_cache["shared_attn"] = sa
     logits = _logits(params, cfg, x)

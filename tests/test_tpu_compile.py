@@ -1,9 +1,11 @@
-"""The main path's Pallas kernels compile for a TPU v5e at real widths.
+"""The main path's Pallas kernels compile for a TPU v5e at real widths,
+and the decode program updates its donated cache in place there.
 
-Nothing runs: each test compiles one kernel for a chip that is described
-(``v5e:2x2``, device 0), not attached, and checks that the Mosaic call is
-in the program.  This is what interpret mode cannot show: block shapes off
-the (8, 128) tiling and unaligned dynamic slices are refused here.  The
+Nothing runs: each test compiles one kernel or program for a chip that is
+described (``v5e:2x2``, device 0), not attached, and checks the compiled
+program.  This is what interpret mode and the CPU backend cannot show:
+block shapes off the (8, 128) tiling and unaligned dynamic slices are
+refused here, and the copies kept are the TPU compiler's.  The
 topology is described inside a fixture, never at import, so every test
 worker collects the same tests and only the one running this file loads
 the TPU compiler.
@@ -16,10 +18,13 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from _cache_copies import whole_stack_copies
+from repro.configs import ARCHS
 from repro.core.comm import wire
 from repro.kernels import grad_pack
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ssd_scan import ssd_chunk_kernel
+from repro.models import decode_step, init_cache, init_params
 
 
 @pytest.fixture(scope="module")
@@ -83,3 +88,30 @@ def test_grad_pack_kernel_compiles_at_4mib(one_chip):
         lambda g, e, s: grad_pack._pallas_pack(g, e, s, n_leaves, interpret=False), f32, f32, seg
     )
     assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize(
+    "name, slots, context",
+    [
+        ("mamba2-130m", 128, 512),  # the served cells' decode program
+        ("tinyllama-1.1b", 8, 256),  # GQA ring
+        ("minicpm3-4b", 8, 256),  # MLA latent cache
+        ("whisper-large-v3", 8, 256),  # read-only cross K/V beside the ring
+        ("zamba2-1.2b", 8, 256),  # SSM stack beside the shared-attention cache
+    ],
+)
+def test_decode_step_updates_donated_cache_in_place(one_chip, name, slots, context):
+    """With the cache donated, the compiled decode program copies no whole
+    stacked cache that it writes (``ssm``, ``kv``): each layer's slice is
+    written back into the buffer that came in.  Real widths: a smoke
+    config's stack is small enough that the compiler stages all of it in
+    on-chip memory, which is a copy of another kind."""
+    cfg = ARCHS[name]
+    place = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    params = place(jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    cache = place(jax.eval_shape(lambda: init_cache(cfg, slots, context)))
+    tokens = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
+    positions = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    fn = jax.jit(lambda p, t, pos, c: decode_step(p, cfg, t, pos, c), donate_argnums=(3,))
+    hlo = fn.lower(params, tokens, positions, cache).compile().as_text()
+    assert whole_stack_copies(hlo, cache) == []
